@@ -40,10 +40,7 @@ def hlo_op_census(fn, *args) -> Counter:
 
 
 def _cost_dict(compiled) -> dict:
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, list):      # older jax: one dict per device
-        cost = cost[0] if cost else {}
-    return cost
+    return compiled.cost_analysis() or {}
 
 
 def bytes_accessed(fn, *args) -> float:

@@ -388,12 +388,20 @@ def _sharded_cells() -> dict:
 
 def sharded_decode_cells(cells: dict, rows: list) -> None:
     """Collect the sharded cells, re-execing this module in a subprocess
-    with forced host devices when this process came up with too few (the
-    XLA device count is frozen at first jax import, so it cannot be raised
-    in-process)."""
+    with forced host devices when this CPU process came up with too few
+    (the XLA device count is frozen at first jax import, so it cannot be
+    raised in-process).  On an accelerator this process already holds the
+    chips, so a child could never reach them: the cells run in-process or
+    not at all."""
     want = max(SHARD_COUNTS)
     if jax.device_count() >= want:
         sub = _sharded_cells()
+    elif jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"sharded decode cells need {want} devices; this "
+            f"{jax.default_backend()} process has {jax.device_count()} and "
+            f"holds them, so no child process can run the cells — use a "
+            f"host with {want} devices, or the CPU")
     else:
         env = dict(os.environ)
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +

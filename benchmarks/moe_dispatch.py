@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.launch.mesh import compat_shard_map, make_mesh
+from repro.launch.mesh import make_mesh
 from repro.parallel.collectives import ring_all_to_all, xla_all_to_all
 from benchmarks.common import emit, time_us, hlo_op_census
 
@@ -21,6 +21,12 @@ from benchmarks.common import emit, time_us, hlo_op_census
 def run() -> list:
     n = min(8, jax.device_count())
     if n < 2:
+        if jax.default_backend() != "cpu":
+            # this process holds the chip: a child could never reach it
+            raise RuntimeError(
+                f"moe_dispatch needs >= 2 devices; this "
+                f"{jax.default_backend()} process has {jax.device_count()} "
+                f"and holds it, so no child process can run the cells")
         # re-exec ourselves with 8 host devices and relay the CSV rows
         import os
         import subprocess
@@ -44,9 +50,9 @@ def run() -> list:
     x = jax.random.normal(jax.random.PRNGKey(0), (n * n, cap, d),
                           dtype=jnp.bfloat16)
 
-    ring = jax.jit(compat_shard_map(lambda a: ring_all_to_all(a, "x"),
+    ring = jax.jit(jax.shard_map(lambda a: ring_all_to_all(a, "x"),
                                  mesh=mesh, in_specs=P("x"), out_specs=P("x")))
-    xla = jax.jit(compat_shard_map(lambda a: xla_all_to_all(a, "x"),
+    xla = jax.jit(jax.shard_map(lambda a: xla_all_to_all(a, "x"),
                                 mesh=mesh, in_specs=P("x"), out_specs=P("x")))
     r1, r2 = np.asarray(ring(x), np.float32), np.asarray(xla(x), np.float32)
     assert np.allclose(r1, r2)

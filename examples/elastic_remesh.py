@@ -21,13 +21,13 @@ if os.environ.get("_REMESH_CHILD") != "1":
 import jax                                     # noqa: E402
 import jax.numpy as jnp                        # noqa: E402
 import numpy as np                             # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.checkpoint import save_checkpoint, restore_checkpoint  # noqa: E402
-from repro.launch.mesh import compat_mesh, make_mesh  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 big = make_mesh((4, 2), ("data", "model"))
-small = compat_mesh(jax.devices()[:4], (2, 2), ("data", "model"))
+small = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
 
 state = {"w": jax.device_put(jnp.arange(64.0).reshape(8, 8),
                              NamedSharding(big, P("data", "model"))),

@@ -22,7 +22,7 @@ import jax.numpy as jnp                        # noqa: E402
 import numpy as np                             # noqa: E402
 from jax.sharding import PartitionSpec as P    # noqa: E402
 
-from repro.launch.mesh import compat_shard_map, make_mesh  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.parallel.collectives import ring_all_to_all, xla_all_to_all  # noqa: E402
 
 E = jax.device_count()                         # experts = devices = ports
@@ -34,10 +34,10 @@ print(f"{E} experts on {E} devices; capacity {CAP} tokens x d={D}")
 # local view [E(block per peer), CAP, D]
 tokens = jax.random.normal(jax.random.PRNGKey(0), (E * E, CAP, D))
 
-ring = jax.jit(compat_shard_map(lambda t: ring_all_to_all(t, "expert"),
+ring = jax.jit(jax.shard_map(lambda t: ring_all_to_all(t, "expert"),
                              mesh=mesh, in_specs=P("expert"),
                              out_specs=P("expert")))
-xla = jax.jit(compat_shard_map(lambda t: xla_all_to_all(t, "expert"),
+xla = jax.jit(jax.shard_map(lambda t: xla_all_to_all(t, "expert"),
                             mesh=mesh, in_specs=P("expert"),
                             out_specs=P("expert")))
 
@@ -45,7 +45,7 @@ a, b = np.asarray(ring(tokens)), np.asarray(xla(tokens))
 assert np.allclose(a, b)
 print("ring schedule (N-1 ppermute rotations) == XLA all-to-all ✓")
 
-txt = jax.jit(compat_shard_map(lambda t: ring_all_to_all(t, "expert"),
+txt = jax.jit(jax.shard_map(lambda t: ring_all_to_all(t, "expert"),
                             mesh=mesh, in_specs=P("expert"),
                             out_specs=P("expert"))).lower(tokens).compile().as_text()
 n_perm = txt.count(" collective-permute(") + txt.count(" collective-permute-start(")

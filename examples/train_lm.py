@@ -13,11 +13,11 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh
 
 from repro.configs.base import ModelConfig, ShapeConfig, TrainConfig
 from repro.checkpoint import CheckpointManager
 from repro.data import SyntheticLM
-from repro.launch.mesh import compat_mesh
 from repro.launch.steps import build_train_step
 from repro.models import api
 from repro.optim import init_opt_state
@@ -35,7 +35,7 @@ cfg = ModelConfig(name="lm-100m", family="dense", n_layers=12, d_model=768,
 print(f"params: {cfg.param_count() / 1e6:.1f}M")
 
 BATCH, SEQ = 8, 128
-mesh = compat_mesh(jax.devices()[:1], (1, 1), ("data", "model"))
+mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
 tcfg = TrainConfig(lr=3e-3, warmup_steps=30, total_steps=args.steps,
                    grad_accum=1, zero1=False)
 built = build_train_step(cfg, ShapeConfig("ex", SEQ, BATCH, "train"),

@@ -159,14 +159,13 @@ def pool_partition_spec(leaf_ndim: int):
 
 def make_pool_mesh(n_shards: int):
     """A 1-D ``("pool",)`` mesh over the first ``n_shards`` devices."""
-    from repro.launch.mesh import compat_mesh
     devices = jax.devices()
     if len(devices) < n_shards:
         raise RuntimeError(
             f"pool mesh needs {n_shards} devices, have {len(devices)} — "
             f"set XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{n_shards} before any jax import")
-    return compat_mesh(devices[:n_shards], (n_shards,), (POOL_AXIS,))
+    return jax.sharding.Mesh(np.asarray(devices[:n_shards]), (POOL_AXIS,))
 
 
 def _exchange(x: jax.Array, collective: str) -> jax.Array:
@@ -189,7 +188,6 @@ def sharded_read_burst(fabric, stream: jax.Array, fetch: jax.Array,
     ``fetch`` names from its local block (the PR-5 kernel when enabled),
     un-banks them to exchange order, runs one collective, and the
     requesting shard places the received lines at their output rows."""
-    from repro.launch.mesh import compat_shard_map
     from jax.sharding import PartitionSpec as P
     n = fabric.n_ports
     s, _, cap = fetch.shape
@@ -206,7 +204,7 @@ def sharded_read_burst(fabric, stream: jax.Array, fetch: jax.Array,
             recv.reshape(s * cap, n, -1), mode="drop")
         return out.reshape(k_loc // n, n, n, -1).swapaxes(1, 2)
 
-    return compat_shard_map(
+    return jax.shard_map(
         body, mesh=fabric.mesh,
         in_specs=(P(None, POOL_AXIS), P(POOL_AXIS), P(POOL_AXIS)),
         out_specs=P(POOL_AXIS), check_vma=False)(stream, fetch, place)
@@ -222,7 +220,6 @@ def sharded_write_burst(fabric, banked: jax.Array, fetch: jax.Array,
     stream; rows the indices never touch keep their frames without moving.
     This is also the disaggregation primitive: a prefill writer targeting a
     remote shard's pool is exactly this lowering."""
-    from repro.launch.mesh import compat_shard_map
     from jax.sharding import PartitionSpec as P
     n = fabric.n_ports
     s, _, cap = fetch.shape
@@ -240,7 +237,7 @@ def sharded_write_burst(fabric, banked: jax.Array, fetch: jax.Array,
                                  into=pool_lines)
         return out.reshape(into_loc.shape)
 
-    return compat_shard_map(
+    return jax.shard_map(
         body, mesh=fabric.mesh,
         in_specs=(P(POOL_AXIS), P(None, POOL_AXIS), P(POOL_AXIS),
                   P(POOL_AXIS)),

@@ -3,8 +3,9 @@
 These are what the framework calls.  Every op:
 
 * validates/pads shapes to kernel tile requirements,
-* dispatches to the Pallas kernel (``interpret=True`` on CPU — the kernel
-  body is identical on TPU, where ``interpret=False`` is used),
+* dispatches to the Pallas kernel, compiled by Mosaic on TPU and run in the
+  Pallas interpreter on every other backend (:func:`interpret_mode` — the
+  one place that choice is made; the kernel body is identical),
 * has a pure-jnp oracle in :mod:`repro.kernels.ref` which tests sweep against.
 
 ``use_kernels(False)`` (or the ``REPRO_NO_KERNELS`` env var) routes every op
@@ -42,6 +43,12 @@ def kernels_enabled() -> bool:
     return _USE_KERNELS
 
 
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in the interpreter: on every backend but
+    TPU.  On a TPU the kernels always compile — nothing falls back."""
+    return jax.default_backend() != "tpu"
+
+
 def _pow2_floor(n: int) -> int:
     p = 1
     while p * 2 <= n:
@@ -59,7 +66,7 @@ def transpose_rc(x: jax.Array, tile: int = 0) -> jax.Array:
         tile = min(_pow2_floor(max(r, 1)), _pow2_floor(max(c, 1)), 64)
     pr, pc = (-r) % tile, (-c) % tile
     xp = jnp.pad(x, ((0, pr), (0, pc), (0, 0))) if (pr or pc) else x
-    out = medusa_transpose_tiles(xp, tile=tile)
+    out = medusa_transpose_tiles(xp, tile=tile, interpret=interpret_mode())
     return out[:c, :r]
 
 
@@ -77,7 +84,7 @@ def interconnect_read(lines: jax.Array, n_ports: int) -> jax.Array:
     if not _USE_KERNELS:
         from repro.core.transpose import read_network_oracle
         return read_network_oracle(lines, n_ports)
-    return read_network_tiles(lines, n_ports)
+    return read_network_tiles(lines, n_ports, interpret=interpret_mode())
 
 
 def burst_read(tile: jax.Array, n_ports: int) -> jax.Array:
@@ -87,7 +94,7 @@ def burst_read(tile: jax.Array, n_ports: int) -> jax.Array:
     if not _USE_KERNELS:
         from repro.core.transpose import read_network_oracle
         return read_network_oracle(tile, n_ports)[0]
-    return burst_network_tiles(tile, n_ports)
+    return burst_network_tiles(tile, n_ports, interpret=interpret_mode())
 
 
 def burst_write(banked: jax.Array, n_ports: int) -> jax.Array:
@@ -97,7 +104,7 @@ def burst_write(banked: jax.Array, n_ports: int) -> jax.Array:
     if not _USE_KERNELS:
         from repro.core.transpose import write_network_oracle
         return write_network_oracle(banked[None], n_ports)
-    return burst_network_tiles(banked, n_ports)
+    return burst_network_tiles(banked, n_ports, interpret=interpret_mode())
 
 
 def burst_gather_read(lines: jax.Array, idx: jax.Array,
@@ -111,7 +118,8 @@ def burst_gather_read(lines: jax.Array, idx: jax.Array,
         from repro.core.transpose import read_network_oracle
         taken = jnp.take(lines, idx, axis=0, mode="fill", fill_value=0)
         return read_network_oracle(taken, n_ports)
-    return gather_burst_network_tiles(lines, idx, n_ports)
+    return gather_burst_network_tiles(lines, idx, n_ports,
+                                      interpret=interpret_mode())
 
 
 def burst_scatter_write(banked: jax.Array, idx: jax.Array, into: jax.Array,
@@ -124,14 +132,15 @@ def burst_scatter_write(banked: jax.Array, idx: jax.Array, into: jax.Array,
         from repro.core.transpose import write_network_oracle
         lines = write_network_oracle(banked, n_ports)
         return into.at[idx].set(lines, mode="drop")
-    return scatter_burst_network_tiles(banked, idx, into, n_ports)
+    return scatter_burst_network_tiles(banked, idx, into, n_ports,
+                                       interpret=interpret_mode())
 
 
 def rotate_groups(x: jax.Array, amounts: jax.Array) -> jax.Array:
     """Barrel-rotate each ``x[g] [N, W]`` left by ``amounts[g]``."""
     if not _USE_KERNELS:
         return jax.vmap(ref.rotate_ref)(x, amounts)
-    return barrel_rotate_groups(x, amounts)
+    return barrel_rotate_groups(x, amounts, interpret=interpret_mode())
 
 
 def matmul(x: jax.Array, w: jax.Array, bm: int = 0, bn: int = 0,
@@ -147,4 +156,5 @@ def matmul(x: jax.Array, w: jax.Array, bm: int = 0, bn: int = 0,
     bk = bk or min(128, _pow2_floor(k))
     if m % bm or n % bn or k % bk:
         return ref.matmul_ref(x, w)
-    return stream_matmul(x, w, bm=bm, bn=bn, bk=bk)
+    return stream_matmul(x, w, bm=bm, bn=bn, bk=bk,
+                         interpret=interpret_mode())

@@ -20,8 +20,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def barrel_rotate_groups(x: jax.Array, amounts: jax.Array,
-                         interpret: bool = True) -> jax.Array:
+def barrel_rotate_groups(x: jax.Array, amounts: jax.Array, *,
+                         interpret: bool) -> jax.Array:
     """Left-rotate each group ``x[g] : [N, W]`` by ``amounts[g]`` positions.
 
     ``N`` must be a power of two.  Grid over groups; the rotation amount is a

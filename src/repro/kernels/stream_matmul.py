@@ -39,7 +39,7 @@ def _matmul_kernel(x_ref, w_ref, o_ref, acc_ref):
 @functools.partial(jax.jit,
                    static_argnames=("bm", "bn", "bk", "interpret"))
 def stream_matmul(x: jax.Array, w: jax.Array, bm: int = 128, bn: int = 128,
-                  bk: int = 128, interpret: bool = True) -> jax.Array:
+                  bk: int = 128, *, interpret: bool) -> jax.Array:
     """``x [M, K] @ w [K, N]`` with K-streaming and fp32 accumulation.
 
     Block shapes are MXU-aligned (multiples of 128 on hardware); the K grid

@@ -35,6 +35,7 @@ import time
 import jax
 
 from repro.configs import get_config, get_smoke
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.runtime.fault_tolerance import FaultInjector
 from repro.serving import (MetricsRecorder, ReplicaRouter, ServingEngine,
@@ -144,6 +145,7 @@ def main():
     ap.add_argument("--bench-out", default="BENCH_serving.json")
     ap.add_argument("--no-bench", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     tcfg = TrafficConfig(

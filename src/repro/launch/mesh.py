@@ -16,29 +16,6 @@ import jax
 import numpy as np
 
 
-def compat_mesh(devices, shape: tuple, axes: tuple):
-    """Construct a Mesh with Auto axis types where this jax supports them
-    (axis_types landed after 0.4.x; Auto is the default behaviour there)."""
-    arr = np.asarray(devices).reshape(shape)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.sharding.Mesh(arr, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-    return jax.sharding.Mesh(arr, axes)
-
-
-def compat_shard_map(f, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` where available, ``jax.experimental.shard_map``
-    otherwise (same semantics; ``check_vma`` was called ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
@@ -49,7 +26,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {n} devices, have {len(devices)} — the "
             f"dry-run must set XLA_FLAGS=--xla_force_host_platform_device_count"
             f"={n} before any jax import")
-    return compat_mesh(devices[:n], shape, axes)
+    return jax.sharding.Mesh(np.asarray(devices[:n]).reshape(shape), axes)
 
 
 def make_mesh(shape: tuple, axes: tuple):
@@ -61,7 +38,7 @@ def make_mesh(shape: tuple, axes: tuple):
             f"mesh {shape} needs {n} devices, have {len(devices)} — set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={n} before "
             f"any jax import")
-    return compat_mesh(devices[:n], shape, axes)
+    return jax.sharding.Mesh(np.asarray(devices[:n]).reshape(shape), axes)
 
 
 # TPU v5e hardware constants used by the roofline analysis (§Roofline).

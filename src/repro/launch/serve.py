@@ -38,6 +38,7 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke
 from repro.data import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 
 
@@ -133,6 +134,7 @@ def main():
                          "(token stream identical to k=0; the census "
                          "reports the acceptance rate)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     if args.kv_layout:

@@ -21,6 +21,7 @@ from repro.configs import get_config, get_smoke, TrainConfig
 from repro.configs.base import ShapeConfig
 from repro.checkpoint import CheckpointManager, latest_step, restore_checkpoint
 from repro.data import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import build_train_step
 from repro.models import api
 from repro.optim import init_opt_state
@@ -29,8 +30,8 @@ from repro.runtime import TrainingRunner, StragglerDetector, FaultInjector
 
 def make_mesh_for(args):
     if args.smoke:
-        from repro.launch.mesh import compat_mesh
-        return compat_mesh(jax.devices()[:1], (1, 1), ("data", "model"))
+        return jax.sharding.Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
+                                 ("data", "model"))
     from repro.launch.mesh import make_production_mesh
     return make_production_mesh(multi_pod=args.multi_pod)
 
@@ -52,6 +53,7 @@ def main():
                     help="inject node failures at these steps (FT demo)")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
     logging.basicConfig(level=logging.INFO)
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)

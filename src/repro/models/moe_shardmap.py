@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.fabric.fabric import Fabric
-from repro.parallel.collectives import axis_size, ring_all_to_all
+from repro.parallel.collectives import ring_all_to_all
 
 
 def moe_apply_shardmap(p, x: jax.Array, cfg, axis_name: str = "model"):
@@ -39,7 +39,7 @@ def moe_apply_shardmap(p, x: jax.Array, cfg, axis_name: str = "model"):
     """
     m = cfg.moe
     fabric = Fabric.for_model(cfg)
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     e_total = m.n_experts_padded
     e_loc = e_total // n
     b, s, d = x.shape

@@ -25,21 +25,13 @@ from jax import lax
 from repro.runtime.compression import int8_quantize, int8_dequantize
 
 
-def axis_size(axis_name: str) -> int:
-    """``lax.axis_size`` where it exists; ``psum(1, axis)`` (also static
-    under shard_map/pmap tracing) on older jax."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def ring_all_to_all(x: jax.Array, axis_name: str) -> jax.Array:
     """All-to-all of ``x [N, ...]`` (block j destined to rank j) using N-1
     rotation steps.  Equivalent to ``lax.all_to_all`` with uniform blocks.
 
     Must run inside ``shard_map``/``pmap`` with ``axis_name`` bound.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     out = jnp.zeros_like(x)
     # my own block stays put
@@ -64,7 +56,7 @@ def xla_all_to_all(x: jax.Array, axis_name: str) -> jax.Array:
 def ring_all_gather(x: jax.Array, axis_name: str) -> jax.Array:
     """All-gather as N-1 neighbour rotations (overlap-friendly weight
     streaming: each step's block can feed compute while the next streams)."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     blocks = [x]
     cur = x
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -92,7 +84,7 @@ def compressed_psum(g: jax.Array, axis_name: str) -> jax.Array:
 
 def dp_grad_mean(grads, axis_name: str, compression: str = "none"):
     """Data-parallel gradient mean with optional compression (shard_map DP)."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if compression == "int8":
         return jax.tree.map(lambda g: compressed_psum(g, axis_name) / n, grads)
     return jax.tree.map(lambda g: lax.pmean(g, axis_name), grads)

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.parallel.collectives import axis_size
 
 
 def pipeline_forward(stage_fn: Callable, stage_params, microbatches,
@@ -32,7 +31,7 @@ def pipeline_forward(stage_fn: Callable, stage_params, microbatches,
     Returns ``[M, mb, ...]`` outputs of the final stage.  Microbatch ``m``
     occupies stage ``s`` at tick ``m + s`` — the diagonal schedule again.
     """
-    p = axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     m = microbatches.shape[0]
     ticks = m + p - 1

@@ -700,32 +700,7 @@ class ServingEngine:
         live = [s for s in range(self.max_slots) if self.active[s] is not None]
         if not live:
             return 0
-        args = (self.params, jnp.asarray(self.tokens), self.kv.caches,
-                jnp.asarray(self.pos))
-        if self.paged and self.fused:
-            live_idx, expand, dense_pos = cm.page_live_plan(
-                self.kv.pool.table, self.page_size, self.t_alloc,
-                self.fabric.n_ports, bucket=self.live_bucket)
-            plan_args = (self.kv.page_table_device(), jnp.asarray(live_idx),
-                         jnp.asarray(expand), jnp.asarray(dense_pos))
-            if self.pool_shards > 1:
-                # host-side split of the live set by owning shard: one
-                # fetch/place plan per distinct leaf rep count (the bucket
-                # capacity quantizes to whole pages to bound retraces)
-                frames = self.kv.pool.n_pages * self.page_size
-                plans = {
-                    reps: shard_plan(live_idx, frames, self.pool_shards,
-                                     self.fabric.n_ports, reps=reps,
-                                     cap_bucket=self.page_size).operands()
-                    for reps in self._shard_reps}
-                logits, new_caches = self._decode(*args, *plan_args, plans)
-            else:
-                logits, new_caches = self._decode(*args, *plan_args)
-        elif self.paged:
-            logits, new_caches = self._decode(
-                *args, self.kv.page_table_device())
-        else:
-            logits, new_caches = self._decode(*args)
+        logits, new_caches = self._decode(*self._decode_args())
         self.kv.update(new_caches)
         self.last_logits = logits[:, 0]
         # commits only ever read row 0 — the real unembedding — so the
@@ -761,6 +736,37 @@ class ServingEngine:
                 self._draft_queue.pop(s, None)
         return len([s for s in range(self.max_slots)
                     if self.active[s] is not None])
+
+    def _decode_args(self) -> tuple:
+        """The jitted decode step's operands for the current batch state."""
+        args = (self.params, jnp.asarray(self.tokens), self.kv.caches,
+                jnp.asarray(self.pos))
+        if self.paged and self.fused:
+            live_idx, expand, dense_pos = cm.page_live_plan(
+                self.kv.pool.table, self.page_size, self.t_alloc,
+                self.fabric.n_ports, bucket=self.live_bucket)
+            args += (self.kv.page_table_device(), jnp.asarray(live_idx),
+                     jnp.asarray(expand), jnp.asarray(dense_pos))
+            if self.pool_shards > 1:
+                # host-side split of the live set by owning shard: one
+                # fetch/place plan per distinct leaf rep count (the bucket
+                # capacity quantizes to whole pages to bound retraces)
+                frames = self.kv.pool.n_pages * self.page_size
+                args += ({
+                    reps: shard_plan(live_idx, frames, self.pool_shards,
+                                     self.fabric.n_ports, reps=reps,
+                                     cap_bucket=self.page_size).operands()
+                    for reps in self._shard_reps},)
+        elif self.paged:
+            args += (self.kv.page_table_device(),)
+        return args
+
+    def decode_step_text(self) -> str:
+        """Optimized HLO text of the decode step for the current batch
+        state (compiled here, or found in the compilation cache): what the
+        device runs, e.g. to check that the Pallas burst kernels are in it
+        (``tpu_custom_call`` on a TPU)."""
+        return self._decode.lower(*self._decode_args()).compile().as_text()
 
     # -- speculative decoding -------------------------------------------------
     def verify_step(self, slot: int, req: Request, committed: int,

@@ -73,7 +73,8 @@ def test_gather_kernel_matches_take_then_network(n, word_tile):
     perm = np.random.RandomState(n).permutation(l)
     idx[: k - 2] = perm[: k - 2]                   # 2 sentinel pads
     idx = jnp.asarray(idx)
-    out = gather_burst_network_tiles(lines, idx, n, word_tile=word_tile)
+    out = gather_burst_network_tiles(lines, idx, n, word_tile=word_tile,
+                                     interpret=True)
     ref = jnp.take(lines, idx, axis=0, mode="fill",
                    fill_value=0).reshape(k // n, n, n, w).swapaxes(1, 2)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
@@ -92,7 +93,7 @@ def test_scatter_kernel_matches_network_then_scatter(n):
     idx = np.full((k,), SENTINEL, np.int32)
     idx[: k - 1] = np.random.RandomState(n).permutation(l)[: k - 1]
     idx = jnp.asarray(idx)
-    out = scatter_burst_network_tiles(banked, idx, pool, n)
+    out = scatter_burst_network_tiles(banked, idx, pool, n, interpret=True)
     lines = banked.swapaxes(1, 2).reshape(k, n, w)
     ref = pool.at[idx].set(lines, mode="drop")
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
@@ -103,25 +104,53 @@ def test_scatter_kernel_matches_network_then_scatter(n):
                                   np.asarray(pool)[untouched])
 
 
+@pytest.mark.parametrize("layout", ["leading", "middle", "all_sentinel"])
+def test_scatter_kernel_sentinel_schedule(layout):
+    """Sentinel runs anywhere in the index list — before the first live
+    row, between live rows, or filling the whole list — drop without
+    touching any pool row, including a live last row (on hardware each
+    sentinel step revisits a live block and writes nothing)."""
+    n, l, w = 4, 24, 6
+    k = 3 * n
+    banked = jax.random.normal(jax.random.fold_in(KEY, 7), (k // n, n, n, w),
+                               jnp.float32)
+    pool = jax.random.normal(jax.random.fold_in(KEY, 8), (l, n, w),
+                             jnp.float32)
+    rows = list(np.random.RandomState(3).permutation(l - 1)[: k - 5])
+    s = [SENTINEL]
+    idx = {"leading": s * 4 + rows + [l - 1],
+           "middle": rows[:3] + s * 2 + [l - 1] + s * 2 + rows[3:],
+           "all_sentinel": s * k}[layout]
+    idx = jnp.asarray(np.asarray(idx, np.int32))
+    out = scatter_burst_network_tiles(banked, idx, pool, n, interpret=True)
+    lines = banked.swapaxes(1, 2).reshape(k, n, w)
+    ref = pool.at[idx].set(lines, mode="drop")
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
 def test_pick_word_tile_respects_gather_block_shape():
     """Regression (odd word_tile × sparse extent): the gather-operand mode
     must return a divisor of the frame word count — a padded edge tile
     would read/write past an indexed frame's extent — while the dense mode
     keeps its padded fallback; a non-dividing explicit tile is a loud
     error, not a silent misread."""
-    assert _pick_word_tile(4099) == 2050                  # pad fallback
-    assert _pick_word_tile(4099, divisor=True) == 1       # prime: worst case
-    assert 4100 % _pick_word_tile(4100, divisor=True) == 0
+    cap = 4096
+    assert _pick_word_tile(4099, cap) == 2176       # even split, 128-aligned
+    assert _pick_word_tile(4099, cap, divisor=True) == 1  # prime: worst case
+    assert 4100 % _pick_word_tile(4100, cap, divisor=True) == 0
+    assert _pick_word_tile(3 * 2048, cap, divisor=True) == 3072  # aligned
     w = 6000                                              # no divisor in (2048, 4096]
-    t = _pick_word_tile(w, divisor=True)
+    t = _pick_word_tile(w, cap, divisor=True)
     assert w % t == 0 and t <= 4096
     lines = jnp.zeros((4, 4, 6), jnp.float32)
     idx = jnp.zeros((4,), jnp.int32)
     with pytest.raises(ValueError, match="word_tile"):
-        gather_burst_network_tiles(lines, idx, 4, word_tile=4)
+        gather_burst_network_tiles(lines, idx, 4, word_tile=4,
+                                   interpret=True)
     with pytest.raises(ValueError, match="word_tile"):
         scatter_burst_network_tiles(jnp.zeros((1, 4, 4, 6), jnp.float32),
-                                    idx, lines, 4, word_tile=4)
+                                    idx, lines, 4, word_tile=4,
+                                    interpret=True)
 
 
 # ---------------------------------------------------------------------------

@@ -17,7 +17,7 @@ from repro.core.transpose import read_network_oracle
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int32])
 def test_transpose_kernel_sweep(r, c, w, tile, dtype):
     x = jnp.arange(r * c * w).reshape(r, c, w).astype(dtype)
-    out = medusa_transpose_tiles(x, tile=tile)
+    out = medusa_transpose_tiles(x, tile=tile, interpret=True)
     np.testing.assert_array_equal(np.asarray(out),
                                   np.asarray(ref.transpose_ref(x)))
 
@@ -34,7 +34,7 @@ def test_transpose_wrapper_padding(r, c, w):
 def test_read_network_kernel(n, g, w):
     lines = jax.random.normal(jax.random.PRNGKey(0), (g * n, n, w))
     np.testing.assert_allclose(
-        np.asarray(read_network_tiles(lines, n)),
+        np.asarray(read_network_tiles(lines, n, interpret=True)),
         np.asarray(read_network_oracle(lines, n)))
 
 
@@ -44,7 +44,7 @@ def test_rotator_kernel(n, w, dtype):
     g = 5
     x = jax.random.normal(jax.random.PRNGKey(1), (g, n, w)).astype(dtype)
     amts = jnp.array([0, 1, n - 1, n, 3])
-    out = barrel_rotate_groups(x, amts)
+    out = barrel_rotate_groups(x, amts, interpret=True)
     for i in range(g):
         np.testing.assert_array_equal(
             np.asarray(out[i]),
@@ -58,7 +58,8 @@ def test_rotator_kernel(n, w, dtype):
 def test_stream_matmul(m, k, n, dtype, tol):
     x = jax.random.normal(jax.random.PRNGKey(2), (m, k)).astype(dtype)
     w = jax.random.normal(jax.random.PRNGKey(3), (k, n)).astype(dtype)
-    out = stream_matmul(x, w, bm=128, bn=128, bk=128)
+    out = stream_matmul(x, w, bm=128, bn=128, bk=128,
+                        interpret=True)
     want = ref.matmul_ref(x, w)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32),

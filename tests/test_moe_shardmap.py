@@ -25,7 +25,7 @@ x = jax.random.normal(jax.random.PRNGKey(1), (N * 2, 4, 32))
 
 ref = moe_apply(p, x, cfg)                      # GSPMD/pjit layer, unsharded
 
-from repro.launch.mesh import compat_shard_map, make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((N,), ("model",))
 
 def body(p_full, xb):
@@ -33,12 +33,12 @@ def body(p_full, xb):
     p_loc = shard_expert_params(p_full, rank, N, cfg)
     return moe_apply_shardmap(p_loc, xb, cfg, "model")
 
-out = compat_shard_map(body, mesh=mesh, in_specs=(P(), P("model")),
+out = jax.shard_map(body, mesh=mesh, in_specs=(P(), P("model")),
                     out_specs=P("model"), check_vma=False)(p, x)
 np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-4)
 
 # and the lowering uses only rotations — no all-to-all, no payload scatter
-txt = jax.jit(compat_shard_map(body, mesh=mesh, in_specs=(P(), P("model")),
+txt = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(), P("model")),
                             out_specs=P("model"), check_vma=False)
               ).lower(p, x).compile().as_text()
 n_perm = txt.count(" collective-permute(") + txt.count(" collective-permute-start(")
@@ -71,7 +71,7 @@ cfg = ModelConfig(name="t", family="moe", n_layers=1, d_model=16, n_heads=2,
                                 capacity_factor=8.0))
 key = jax.random.PRNGKey(0)
 p = moe_params(key, cfg, jnp.float32)
-from repro.launch.mesh import compat_shard_map, make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((N,), ("model",))
 x = jax.random.normal(jax.random.PRNGKey(1), (N * 2, 4, 16))
 target = jnp.tanh(x @ jax.random.normal(jax.random.PRNGKey(2), (16, 16)))
@@ -82,7 +82,7 @@ def loss_fn(p_full, xb, tb):
     out = moe_apply_shardmap(p_loc, xb, cfg, "model")
     return jax.lax.pmean(jnp.mean((out - tb) ** 2), "model")
 
-smap = compat_shard_map(loss_fn, mesh=mesh, in_specs=(P(), P("model"), P("model")),
+smap = jax.shard_map(loss_fn, mesh=mesh, in_specs=(P(), P("model"), P("model")),
                      out_specs=P(), check_vma=False)
 step = jax.jit(jax.value_and_grad(lambda p_: smap(p_, x, target)))
 losses = []

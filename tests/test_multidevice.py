@@ -24,12 +24,12 @@ def test_ring_all_to_all_equals_xla():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.parallel.collectives import ring_all_to_all, xla_all_to_all
-from repro.launch.mesh import compat_shard_map, make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((8,), ("x",))
 x = jax.random.normal(jax.random.PRNGKey(0), (64, 4))
-ring = compat_shard_map(lambda a: ring_all_to_all(a, "x"), mesh=mesh,
+ring = jax.shard_map(lambda a: ring_all_to_all(a, "x"), mesh=mesh,
                      in_specs=P("x"), out_specs=P("x"))
-xla = compat_shard_map(lambda a: xla_all_to_all(a, "x"), mesh=mesh,
+xla = jax.shard_map(lambda a: xla_all_to_all(a, "x"), mesh=mesh,
                     in_specs=P("x"), out_specs=P("x"))
 np.testing.assert_allclose(np.asarray(ring(x)), np.asarray(xla(x)))
 print("OK")
@@ -42,7 +42,7 @@ def test_shard_map_dp_with_compression():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.parallel.collectives import dp_grad_mean
-from repro.launch.mesh import compat_shard_map, make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((8,), ("dp",))
 w = jnp.ones((16,))
 def step(w, xb):
@@ -50,7 +50,7 @@ def step(w, xb):
     g = jax.grad(lambda w: jnp.sum((xb @ w.reshape(16, 1)) ** 2))(w)
     return dp_grad_mean({"w": g}, "dp", compression="int8")["w"]
 x = jax.random.normal(jax.random.PRNGKey(0), (32, 16))
-out = compat_shard_map(step, mesh=mesh, in_specs=(P(), P("dp")),
+out = jax.shard_map(step, mesh=mesh, in_specs=(P(), P("dp")),
                     out_specs=P(), check_vma=False)(w, x)
 ref = jax.grad(lambda w: jnp.mean(jax.vmap(
     lambda xb: jnp.sum((xb @ w.reshape(16, 1)) ** 2))(x.reshape(8, 4, 16))))(w)

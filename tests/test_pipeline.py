@@ -13,7 +13,7 @@ from jax.sharding import PartitionSpec as P
 from repro.parallel.pipeline import pipeline_forward, pipeline_loss, bubble_fraction
 
 P_STAGES, M, MB, D = 4, 6, 2, 8
-from repro.launch.mesh import compat_shard_map, make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((P_STAGES,), ("pipe",))
 ws = jax.random.normal(jax.random.PRNGKey(0), (P_STAGES, D, D)) * 0.3
 xs = jax.random.normal(jax.random.PRNGKey(1), (M, MB, D))
@@ -24,7 +24,7 @@ stage = lambda w, x: jnp.tanh(x @ w[0])
 def run(ws_all, xs):
     return pipeline_forward(stage, ws_all, xs, "pipe")
 
-piped = compat_shard_map(run, mesh=mesh, in_specs=(P("pipe"), P()),
+piped = jax.shard_map(run, mesh=mesh, in_specs=(P("pipe"), P()),
                       out_specs=P(), check_vma=False)(ws, xs)
 
 seq = xs
@@ -34,7 +34,7 @@ np.testing.assert_allclose(np.asarray(piped), np.asarray(seq), atol=1e-5)
 
 # backward: grads through the pipeline match sequential grads
 def loss_piped(ws_all):
-    f = compat_shard_map(
+    f = jax.shard_map(
         lambda w, x, t: pipeline_loss(stage, lambda o, t: jnp.mean((o - t) ** 2),
                                       w, x, t, "pipe")[None],
         mesh=mesh, in_specs=(P("pipe"), P(), P()), out_specs=P(None),

@@ -5,12 +5,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs import get_smoke
 from repro.configs.base import TrainConfig, ShapeConfig
 from repro.data import SyntheticLM
-from repro.launch.mesh import compat_mesh
 from repro.launch.steps import (build_train_step, build_prefill_step,
                                 build_decode_step, make_sharder, param_specs,
                                 zero1_specs, _eval_params)
@@ -19,7 +18,8 @@ from repro.parallel.sharding import Sharder, rules_for
 
 
 def _mesh11():
-    return compat_mesh(jax.devices()[:1], (1, 1), ("data", "model"))
+    return Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
+                ("data", "model"))
 
 
 def test_spec_mapping():
@@ -47,7 +47,7 @@ def test_param_specs_cover_tree():
 
 
 def test_zero1_adds_data_axis():
-    mesh = compat_mesh(jax.devices()[:1], (1, 1), ("data", "model"))
+    mesh = _mesh11()
     # fake 4-way data mesh via rules only (structure test, mesh is 1x1)
     cfg = get_smoke("stablelm-1.6b")
     sharder = make_sharder(cfg, mesh)

@@ -168,8 +168,7 @@ def test_word_view_u64_under_x64():
     """The 8-byte ``_WORD_VIEW`` entry: float64 payloads ride the u64
     integer-view fast path when x64 is enabled (they used to silently skip
     it), and bf16 groups fold x4 into u64 lanes."""
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         n = 4
         f64 = jax.random.normal(KEY, (2 * n, n, 6), jnp.float64)
         assert sched_mod._int_view(f64).dtype == jnp.uint64
@@ -199,17 +198,18 @@ def test_word_view_f64_skips_without_x64():
 # fused burst kernel
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("n,w", [(2, 6), (4, 37), (8, 129), (8, 4097)])
+@pytest.mark.parametrize("n,w", [(2, 6), (4, 37), (8, 129), (8, 4097),
+                                 (8, 16385)])
 def test_burst_network_tiles_matches_oracle(n, w):
     """The single-kernel burst (word-tiled grid, pad-and-slice for widths
     past the tile cap) is the read network on one [N, N, W] tile — and its
     own inverse (write direction)."""
     x = jax.random.randint(jax.random.fold_in(KEY, w), (n, n, w), 0, 2**16,
                            jnp.uint32).astype(jnp.uint16)
-    out = burst_network_tiles(x, n)
+    out = burst_network_tiles(x, n, interpret=True)
     np.testing.assert_array_equal(np.asarray(out),
                                   np.asarray(read_network_oracle(x, n)[0]))
-    back = burst_network_tiles(out, n)
+    back = burst_network_tiles(out, n, interpret=True)
     np.testing.assert_array_equal(np.asarray(back), np.asarray(x))
 
 
