@@ -76,6 +76,17 @@ production-shaped traffic harness driving all of this lives in
 lifecycle stamps, in-process replica router) with the
 ``launch/loadgen.py`` CLI on top.
 
+**Spans** (``jax.profiler.TraceAnnotation``) mark each step's host phases
+in the profiler's own trace, on the device planes' clock: ``serve.step``
+round :meth:`step`; inside it ``serve.admit`` (one ``serve.prefill`` per
+fresh request, metadata ``rid`` and ``prompt_len``, then ``serve.install``
+for the wave's burst), ``serve.plan`` (the live-frame plan, metadata
+``live`` and ``bucket`` frames), ``serve.decode`` (the jitted step's
+dispatch), ``serve.sample`` (the host waiting on the argmax) and
+``serve.commit``.  With no profiler running a span records nothing and no
+compiled program changes.  ``Request.admitted_s`` stamps the host clock
+(``time.perf_counter``) as a fresh request's prefill starts.
+
 Decoder-only families (dense/moe/ssm/hybrid/vlm); greedy sampling.
 """
 
@@ -83,16 +94,19 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import time
 from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.fabric import (BurstScheduler, Fabric, PagedKVCache,
                           SchedulerStats, SwapRecord, make_pool_mesh,
                           shard_plan)
+from repro.fabric.scheduler import FRAME_SENTINEL
 from repro.models import api
 from repro.models import common as cm
 from repro.models import lm
@@ -119,6 +133,8 @@ class Request:                             # array makes field-eq ambiguous
     arrival_step: int = -1                 # engine step at submit() — the
     #                                        clock for queue wait and aging
     shed_reason: Optional[str] = None      # set when load-shed, never served
+    admitted_s: Optional[float] = None     # time.perf_counter() as its
+    #                                        prefill starts (fresh admission)
     _seq: int = dataclasses.field(default=0, repr=False)   # submit order
 
 
@@ -515,41 +531,44 @@ class ServingEngine:
         candidate outranks live work, preempts victims instead of waiting
         (:meth:`_make_room`).  An injected pool-exhaustion fault backs the
         whole wave off for the step."""
-        self._shed_unmeetable_queued()
-        if (self.kv.paged and self.fault_injector is not None
-                and self.fault_injector.pool_exhausted(self._step_count)):
-            return
-        wave: list = []
-        protected: set = set()         # slots filled this wave — not victims
-        while True:
-            cands = self._candidates()
-            if not cands:
-                break
-            cand = cands[0]
-            req = cand.req if isinstance(cand, _Swapped) else cand
-            free = [s for s in range(self.max_slots)
-                    if self.active[s] is None]
-            if self.kv.paged:
-                # reserve the request's full reach (prompt + generation,
-                # capped by the cache depth) so decode growth can never
-                # exhaust the pool mid-flight — admission is the only gate
-                reach = min(len(req.prompt) + req.max_new_tokens, self.t_max)
-                need = self.kv.table.pages_for(reach)
-                if not free or self._pool_headroom() < need:
-                    if not self._make_room(req, need, protected,
-                                           have_slot=bool(free)):
-                        break        # wait for pages to be reclaimed
-                    free = [s for s in range(self.max_slots)
-                            if self.active[s] is None]
-                self._page_reserve[free[0]] = need
-            elif not free:
-                break
-            slot = free[0]
-            protected.add(slot)
-            self._install(cand, slot, wave)
-        if wave:
-            self.kv.admit_wave(wave, stats=self.fabric_stats,
-                               burst=self.prefill_burst)
+        with TraceAnnotation("serve.admit"):
+            self._shed_unmeetable_queued()
+            if (self.kv.paged and self.fault_injector is not None
+                    and self.fault_injector.pool_exhausted(self._step_count)):
+                return
+            wave: list = []
+            protected: set = set()     # slots filled this wave: not victims
+            while True:
+                cands = self._candidates()
+                if not cands:
+                    break
+                cand = cands[0]
+                req = cand.req if isinstance(cand, _Swapped) else cand
+                free = [s for s in range(self.max_slots)
+                        if self.active[s] is None]
+                if self.kv.paged:
+                    # reserve the request's full reach (prompt + generation,
+                    # capped by the cache depth) so decode growth can never
+                    # exhaust the pool mid-flight: admission is the only gate
+                    reach = min(len(req.prompt) + req.max_new_tokens,
+                                self.t_max)
+                    need = self.kv.table.pages_for(reach)
+                    if not free or self._pool_headroom() < need:
+                        if not self._make_room(req, need, protected,
+                                               have_slot=bool(free)):
+                            break        # wait for pages to be reclaimed
+                        free = [s for s in range(self.max_slots)
+                                if self.active[s] is None]
+                    self._page_reserve[free[0]] = need
+                elif not free:
+                    break
+                slot = free[0]
+                protected.add(slot)
+                self._install(cand, slot, wave)
+            if wave:
+                with TraceAnnotation("serve.install"):
+                    self.kv.admit_wave(wave, stats=self.fabric_stats,
+                                       burst=self.prefill_burst)
 
     def _install(self, cand, slot: int, wave: list) -> None:
         """Land one candidate in ``slot``: fresh requests prefill into the
@@ -580,14 +599,17 @@ class ServingEngine:
         else:
             req = cand
             self.queue.remove(req)
-            prompt = jnp.asarray(req.prompt)[None, :]
-            logits, req_cache = api.prefill_fn(
-                self.params, {"tokens": prompt}, self.cfg, self.t_alloc)
-            # page remap: only the pages the prompt occupies move
-            wave.append((slot, req_cache, len(req.prompt)))
-            self.active[slot] = req
-            self.pos[slot] = len(req.prompt)
-            first = int(np.argmax(np.asarray(logits[0, -1])))
+            req.admitted_s = time.perf_counter()
+            with TraceAnnotation("serve.prefill", rid=req.rid,
+                                 prompt_len=len(req.prompt)):
+                prompt = jnp.asarray(req.prompt)[None, :]
+                logits, req_cache = api.prefill_fn(
+                    self.params, {"tokens": prompt}, self.cfg, self.t_alloc)
+                # page remap: only the pages the prompt occupies move
+                wave.append((slot, req_cache, len(req.prompt)))
+                self.active[slot] = req
+                self.pos[slot] = len(req.prompt)
+                first = int(np.argmax(np.asarray(logits[0, -1])))
             req.generated.append(first)
             self.tokens[slot, 0] = first
             if self.recorder is not None:
@@ -678,20 +700,22 @@ class ServingEngine:
         the replay is deterministic, so recovery is bit-exact.  With
         ``check_pool`` the free-list conservation invariant runs after
         every step."""
-        step_no = self._step_count
-        snap = self._snapshot() if self.fault_injector is not None else None
-        try:
-            n_live = self._step_inner(step_no)
-        except RuntimeError:
-            if snap is None:
-                raise
-            self._restore(snap)
-            self.fabric_stats.faults_recovered += 1
-            n_live = self._step_inner(step_no)
-        self._step_count = step_no + 1
-        if self.check_pool and self.kv.paged:
-            self.kv.pool.check()
-        return n_live
+        with TraceAnnotation("serve.step"):
+            step_no = self._step_count
+            snap = (self._snapshot() if self.fault_injector is not None
+                    else None)
+            try:
+                n_live = self._step_inner(step_no)
+            except RuntimeError:
+                if snap is None:
+                    raise
+                self._restore(snap)
+                self.fabric_stats.faults_recovered += 1
+                n_live = self._step_inner(step_no)
+            self._step_count = step_no + 1
+            if self.check_pool and self.kv.paged:
+                self.kv.pool.check()
+            return n_live
 
     def _step_inner(self, step_no: int) -> int:
         self._admit()
@@ -700,66 +724,75 @@ class ServingEngine:
         live = [s for s in range(self.max_slots) if self.active[s] is not None]
         if not live:
             return 0
-        logits, new_caches = self._decode(*self._decode_args())
+        args = self._decode_args()
+        with TraceAnnotation("serve.decode"):
+            logits, new_caches = self._decode(*args)
         self.kv.update(new_caches)
-        self.last_logits = logits[:, 0]
-        # commits only ever read row 0 — the real unembedding — so the
-        # token stream is the dense engine's regardless of spec_decode_k
-        nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1), np.int32)
-        drafts = None
-        if self._model_draft:
-            drafts = np.asarray(
-                jnp.argmax(logits[:, 1:1 + self.spec_k], axis=-1), np.int32)
-        for s in live:
-            req = self.active[s]
-            self.pos[s] += 1
-            self.kv.extend(s, int(self.pos[s]))
-            req.generated.append(int(nxt[s]))
-            self.tokens[s, 0] = int(nxt[s])
-            if self.spec_k:
-                self.verify_step(s, req, int(nxt[s]),
-                                 None if drafts is None else drafts[s])
-            if (len(req.generated) >= req.max_new_tokens
-                    or self.pos[s] + 1 >= self.t_max):
-                req.done = True
-                if req.deadline is not None and step_no > req.deadline:
-                    self.fabric_stats.slo_missed_served += 1
-                if self.recorder is not None:
-                    self.recorder.record_retire(req, step_no)
-                self.active[s] = None
-                # return the slot's pages (true reclamation in pool mode);
-                # stale frames are masked by the per-slot positions and
-                # overwritten on the next admission
-                self.kv.free(s)
-                self._page_reserve.pop(s, None)
-                self._admitted_at.pop(s, None)
-                self._draft_queue.pop(s, None)
+        with TraceAnnotation("serve.sample"):
+            self.last_logits = logits[:, 0]
+            # commits only ever read row 0 — the real unembedding — so the
+            # token stream is the dense engine's regardless of spec_decode_k
+            nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1), np.int32)
+            drafts = None
+            if self._model_draft:
+                drafts = np.asarray(jnp.argmax(
+                    logits[:, 1:1 + self.spec_k], axis=-1), np.int32)
+        with TraceAnnotation("serve.commit"):
+            for s in live:
+                req = self.active[s]
+                self.pos[s] += 1
+                self.kv.extend(s, int(self.pos[s]))
+                req.generated.append(int(nxt[s]))
+                self.tokens[s, 0] = int(nxt[s])
+                if self.spec_k:
+                    self.verify_step(s, req, int(nxt[s]),
+                                     None if drafts is None else drafts[s])
+                if (len(req.generated) >= req.max_new_tokens
+                        or self.pos[s] + 1 >= self.t_max):
+                    req.done = True
+                    if req.deadline is not None and step_no > req.deadline:
+                        self.fabric_stats.slo_missed_served += 1
+                    if self.recorder is not None:
+                        self.recorder.record_retire(req, step_no)
+                    self.active[s] = None
+                    # return the slot's pages (true reclamation, pool mode);
+                    # stale frames are masked by the per-slot positions and
+                    # overwritten on the next admission
+                    self.kv.free(s)
+                    self._page_reserve.pop(s, None)
+                    self._admitted_at.pop(s, None)
+                    self._draft_queue.pop(s, None)
         return len([s for s in range(self.max_slots)
                     if self.active[s] is not None])
 
     def _decode_args(self) -> tuple:
         """The jitted decode step's operands for the current batch state."""
-        args = (self.params, jnp.asarray(self.tokens), self.kv.caches,
-                jnp.asarray(self.pos))
-        if self.paged and self.fused:
-            live_idx, expand, dense_pos = cm.page_live_plan(
-                self.kv.pool.table, self.page_size, self.t_alloc,
-                self.fabric.n_ports, bucket=self.live_bucket)
-            args += (self.kv.page_table_device(), jnp.asarray(live_idx),
-                     jnp.asarray(expand), jnp.asarray(dense_pos))
-            if self.pool_shards > 1:
-                # host-side split of the live set by owning shard: one
-                # fetch/place plan per distinct leaf rep count (the bucket
-                # capacity quantizes to whole pages to bound retraces)
-                frames = self.kv.pool.n_pages * self.page_size
-                args += ({
-                    reps: shard_plan(live_idx, frames, self.pool_shards,
-                                     self.fabric.n_ports, reps=reps,
-                                     cap_bucket=self.page_size).operands()
-                    for reps in self._shard_reps},)
-        elif self.paged:
-            args += (self.kv.page_table_device(),)
-        return args
+        with TraceAnnotation("serve.plan") as span:
+            args = (self.params, jnp.asarray(self.tokens), self.kv.caches,
+                    jnp.asarray(self.pos))
+            if self.paged and self.fused:
+                live_idx, expand, dense_pos = cm.page_live_plan(
+                    self.kv.pool.table, self.page_size, self.t_alloc,
+                    self.fabric.n_ports, bucket=self.live_bucket)
+                # frames the step's bursts move, of the bucket they walk
+                span.set_metadata(
+                    live=int(np.count_nonzero(live_idx != FRAME_SENTINEL)),
+                    bucket=len(live_idx))
+                args += (self.kv.page_table_device(), jnp.asarray(live_idx),
+                         jnp.asarray(expand), jnp.asarray(dense_pos))
+                if self.pool_shards > 1:
+                    # host-side split of the live set by owning shard: one
+                    # fetch/place plan per distinct leaf rep count (the bucket
+                    # capacity quantizes to whole pages to bound retraces)
+                    frames = self.kv.pool.n_pages * self.page_size
+                    args += ({
+                        reps: shard_plan(live_idx, frames, self.pool_shards,
+                                         self.fabric.n_ports, reps=reps,
+                                         cap_bucket=self.page_size).operands()
+                        for reps in self._shard_reps},)
+            elif self.paged:
+                args += (self.kv.page_table_device(),)
+            return args
 
     def decode_step_text(self) -> str:
         """Optimized HLO text of the decode step for the current batch
