@@ -119,7 +119,10 @@ class SchedulerStats:
     words instead — a fold of 2 folds away half of a burst's elements), so
     ``words_moved - words_folded`` is the post-fold lane traffic the network
     actually touches (for the pad layout the fold rides the padded width,
-    so folded counts include padding riding wider lanes).  ``kernel_bursts``
+    so folded counts include padding riding wider lanes).  ``words_written``
+    is the write direction's share of ``words_moved`` (a fused decode step
+    writes its fresh frames only, so its share does not grow with the live
+    frames its reads carry).  ``kernel_bursts``
     counts the network calls that lowered through the fused single-kernel
     burst path (:meth:`repro.fabric.Fabric.read_burst` with kernels
     enabled).  ``prefill_bursts`` counts admission waves the serving engine
@@ -191,6 +194,7 @@ class SchedulerStats:
     flushes: int = 0
     network_calls: int = 0
     words_moved: int = 0
+    words_written: int = 0
     words_padded: int = 0
     words_folded: int = 0
     words_live: int = 0
@@ -519,6 +523,11 @@ class BurstScheduler:
             out.update(res)
         return out
 
+    def _count_moved(self, elems: int, read: bool) -> None:
+        self.stats.words_moved += elems
+        if not read:
+            self.stats.words_written += elems
+
     def _materialize_gather(self, q: _Queued) -> _Queued:
         """Unrolled-path form of a sparse read: the frame gather lowers as a
         take (sentinels fill zero frames) whose result joins the shared
@@ -546,7 +555,7 @@ class BurstScheduler:
         self.stats.network_calls += 1
         self.stats.kernel_bursts += 1
         self.stats.gather_fused_bursts += 1
-        self.stats.words_moved += elems
+        self._count_moved(elems, read)
         self.stats.words_folded += elems - elems // fold
         wide = (machine_word_dtype(
             jnp.dtype(q.payload.dtype).itemsize * fold) if fold > 1 else None)
@@ -589,7 +598,7 @@ class BurstScheduler:
         self.stats.gather_fused_bursts += 1
         if self.fabric.burst_kernelized_for(q.payload.dtype):
             self.stats.kernel_bursts += 1
-        self.stats.words_moved += elems
+        self._count_moved(elems, read)
         self.stats.words_folded += elems - elems // fold
         # the exchange moves whole padded buckets; the diagonal stays local
         self.stats.words_cross_shard += s * (s - 1) * cap * n * q.width
@@ -670,7 +679,7 @@ class BurstScheduler:
         for q in streams:
             tiles.append(_pack_tile(q, n, fold))
             elems = q.groups * n * n * q.width
-            self.stats.words_moved += elems
+            self._count_moved(elems, read)
             self.stats.words_folded += elems - elems // fold
         burst = tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, axis=-1)
         moved = (self.fabric.read_burst(burst) if read
@@ -717,7 +726,7 @@ class BurstScheduler:
             lead = q.payload.shape[:2] if read else q.payload.shape[:3]
             x = q.payload.reshape(lead + (q.width,))
             lines = q.payload.shape[0] * (1 if read else n)
-            self.stats.words_moved += lines * n * q.width
+            self._count_moved(lines * n * q.width, read)
             self.stats.words_padded += lines * n * (w_max - q.width)
             if q.width < w_max:
                 pad = [(0, 0)] * (x.ndim - 1) + [(0, w_max - q.width)]

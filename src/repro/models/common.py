@@ -405,6 +405,19 @@ def pool_rep_indices(idx: jax.Array, reps: int, frames: int) -> jax.Array:
                      jnp.int32(_SENTINEL)).reshape(-1)
 
 
+def step_frame_indices(live_idx: jax.Array, expand: jax.Array,
+                       pos: jax.Array) -> jax.Array:
+    """Each slot's physical frame for the position a decode step writes,
+    ``live_idx[expand[b, pos[b]]]`` over the :func:`page_live_plan`
+    operands (``pos`` scalar or [B], clamped into the dense depth as the
+    step's own update clamps it).  An unmapped position — an idle slot's
+    included — gives the sentinel.  Returns ``[B]``."""
+    b, t = expand.shape
+    at = jnp.clip(jnp.broadcast_to(pos, (b,)), 0, t - 1)
+    compact = jnp.take_along_axis(expand, at[:, None], axis=1)[:, 0]
+    return jnp.take(live_idx, compact, mode="fill", fill_value=_SENTINEL)
+
+
 def gather_pool_frames(pool_flat: jax.Array, phys: jax.Array,
                        axis: int) -> jax.Array:
     """Gather per-slot frames from a flattened frame axis at ``axis``:
@@ -436,20 +449,22 @@ def scatter_pool_frames(pool_flat: jax.Array, dense: jax.Array,
     return pool_flat.at[tuple(idx)].set(upd, mode="drop")
 
 
-def _pm_cache_write(cache_pm: jax.Array, new: jax.Array,
+def pm_cache_write(cache_pm: jax.Array, new: jax.Array,
                     pos: jax.Array) -> jax.Array:
-    """Write the new token's K/V at ``pos`` directly in port-major space
-    (``cache_pm [B, Hkv, T, D]``, ``new [B, 1, Hkv, D]``; pos scalar or [B]).
+    """Write each slot's new K/V frame at ``pos`` directly in port-major
+    space: ``cache_pm [..., B, Hkv, T, D]``, ``new [..., B, Hkv, D]``, pos
+    scalar or [B]; leading axes (a layer stack) ride along.
 
     Banking is a permutation, so updating after banking is bit-identical to
     the unscheduled path's update-then-bank."""
-    new_pm = jnp.swapaxes(new, 1, 2)
+    upd = new[..., None, :]                       # [..., B, Hkv, 1, D]
     if pos.ndim == 0:
-        return jax.lax.dynamic_update_slice_in_dim(cache_pm, new_pm, pos,
-                                                   axis=2)
-    return jax.vmap(lambda c, u, p:
-                    jax.lax.dynamic_update_slice_in_dim(c, u, p, axis=1)
-                    )(cache_pm, new_pm, pos)
+        return jax.lax.dynamic_update_slice_in_dim(cache_pm, upd, pos,
+                                                   axis=cache_pm.ndim - 2)
+    b_axis = cache_pm.ndim - 4
+    return jax.vmap(lambda c, u, p: jax.lax.dynamic_update_slice_in_dim(
+        c, u, p, axis=c.ndim - 2), in_axes=(b_axis, b_axis, 0),
+        out_axes=b_axis)(cache_pm, upd, pos)
 
 
 def attention_apply_banked(p, x, cfg, *, positions, layer_kind: str,
@@ -461,14 +476,17 @@ def attention_apply_banked(p, x, cfg, *, positions, layer_kind: str,
     burst by the scheduler.  The new token's K/V is written at ``pos`` in
     port-major space and attention runs on the updated port-major cache —
     bit-identical to :func:`attention_apply`'s cached branch, which updates
-    line-major and re-banks per layer.  Returns ``(out, {"k_pm", "v_pm"})``;
-    the step's write burst converts the updated caches back to line-major
-    once for every layer."""
+    line-major and re-banks per layer.  Returns ``(out, {"k_new",
+    "v_new"})``: the step's fresh frames ``[B, Hkv, D]``, not the updated
+    view — the step's write burst carries only those (or rebuilds the
+    updated view from them outside the layer scan, see
+    :func:`repro.models.lm.decode_step`)."""
     q, k, v, window = _qkv_project(p, x, cfg, positions=positions,
                                    layer_kind=layer_kind)
     pos = cache["pos"]
-    ck_p = _pm_cache_write(cache["k_pm"], k, pos)
-    cv_p = _pm_cache_write(cache["v_pm"], v, pos)
+    k_new, v_new = k[:, 0], v[:, 0]
+    ck_p = pm_cache_write(cache["k_pm"], k_new, pos)
+    cv_p = pm_cache_write(cache["v_pm"], v_new, pos)
     ck_p = shard(ck_p, "batch", "kv_heads", "kv_seq", "head_dim")
     cv_p = shard(cv_p, "batch", "kv_heads", "kv_seq", "head_dim")
     t = ck_p.shape[2]
@@ -476,7 +494,7 @@ def attention_apply_banked(p, x, cfg, *, positions, layer_kind: str,
     valid = (kv_pos <= pos if pos.ndim == 0
              else kv_pos[None, :] <= pos[:, None])
     out = _decode_attention(q, ck_p, cv_p, pos, kv_pos, valid, window)
-    return _attn_output(p, out), {"k_pm": ck_p, "v_pm": cv_p}
+    return _attn_output(p, out), {"k_new": k_new, "v_new": v_new}
 
 
 def _cache_write(cache: jax.Array, new: jax.Array, pos: jax.Array) -> jax.Array:
@@ -548,7 +566,14 @@ def _decode_attention(q, k_pm, v_pm, pos, kv_pos, valid, window):
     """Single-step decode attention over a port-major cache.
 
     ``q [B,1,H,D]``, ``k_pm/v_pm [B,Hkv,T,D]``.  Cache-side dots in cache
-    dtype (see ``_decode_attention_linemajor``)."""
+    dtype (see ``_decode_attention_linemajor``).
+
+    The operands pass an optimization barrier, so the attention compiles
+    alike whatever produced its port-major cache (the per-layer path's
+    update-then-bank, or the scheduled step's update in port-major space):
+    left free, XLA fuses each producer into the score and softmax loops
+    differently and the two paths round apart by about 1e-6 in float32."""
+    q, k_pm, v_pm = jax.lax.optimization_barrier((q, k_pm, v_pm))
     b, sq, h, d = q.shape
     hkv = k_pm.shape[1]
     g = h // hkv

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.fabric.scheduler import FRAME_SENTINEL
 from repro.models import common as cm
 from repro.models.moe import moe_params, moe_apply
 from repro.models.mamba2 import mamba_params, mamba_apply
@@ -175,7 +176,7 @@ def _block_apply(t: str, bp: dict, x, cfg: ModelConfig, *, positions,
         if pm_cache is not None:
             # burst-scheduled decode: this layer's cache arrived port-major
             # from the step's shared read burst; attend/update in that form
-            # and let the step's write burst restore line-major afterwards.
+            # and hand the fresh K/V frames to the step's write burst.
             qpos = pos[None] if pos.ndim == 0 else pos[:, None]
             h, new_cache = cm.attention_apply_banked(
                 bp["attn"], h, cfg, positions=qpos, layer_kind=t,
@@ -347,11 +348,13 @@ def decode_step(params, token, caches, pos, cfg: ModelConfig, sched=None,
     With a :class:`repro.fabric.BurstScheduler` (``sched``), every
     full-attention leaf's port-major conversion is hoisted out of the layer
     scan into one shared read burst at the top of the step, attention runs
-    (and the new token's K/V is written) in port-major space, and one write
-    burst restores line-major caches at the bottom — 1 read + 1 write
-    network invocation per dtype per step instead of 2 conversions per
-    layer, bit-identical because banking is a permutation that commutes
-    with the single-timestep update.  Falls back to the per-layer path when
+    (and the new token's K/V is written) in port-major space, the scan
+    emits each layer's fresh K/V frames, and one write burst at the bottom
+    restores the updated line-major caches, rebuilt from those frames
+    outside the scan — 1 read + 1 write network invocation per dtype per
+    step instead of 2 conversions per layer, bit-identical because banking
+    is a permutation that commutes with the single-timestep update.  Falls
+    back to the per-layer path when
     the fabric is not on the port-per-KV-head geometry or a leaf's line
     count does not divide N.
 
@@ -371,9 +374,12 @@ def decode_step(params, token, caches, pos, cfg: ModelConfig, sched=None,
     fused_gather``), the logical→physical gather is fused into the burst
     contract instead: the scheduler's sparse-extent streams bank ONLY the
     live frames the table maps (indices prefetched into the fused burst
-    kernel on the kernelized medusa fabric), so the network's traffic
-    scales with live tokens rather than pool capacity — bit-identical to
-    both the gather-after-burst form and the dense engine.
+    kernel on the kernelized medusa fabric), so the read network's traffic
+    scales with live tokens rather than pool capacity.  The write burst
+    then carries only the step's fresh frames — one per slot per layer, at
+    ``live_idx[expand[b, pos[b]]]`` — and lands them in the pool, whose
+    every other frame stays as it was: bit-identical to both the
+    gather-after-burst form and the dense engine.
 
     With ``shard_plans`` (``{reps: (fetch, place)}`` device operands from
     :func:`repro.fabric.shard_plan`, one per distinct leaf rep count —
@@ -453,9 +459,12 @@ def _decode_step_scheduled(params, token, caches, pos, positions,
     Burst 1 (read network): every planned KV leaf — and, under
     ``cfg.serve_fsdp``, every streamable weight leaf (the ZeRO-1 weight
     all-gather traffic) — moves through one read invocation per dtype.
-    Burst 2 (write network): the updated port-major caches return to
-    line-major.  The issue()/commit() split keeps the transfers overlappable
-    with consumer compute under JAX async dispatch / XLA scheduling.
+    The layer scan attends on the banked views and emits only each layer's
+    fresh K/V frames ``[lead?, B, Hkv, D]``.  Burst 2 (write network): the
+    updated port-major caches, rebuilt from those frames outside the scan,
+    return to line-major.  The issue()/commit() split keeps the transfers
+    overlappable with consumer compute under JAX async dispatch / XLA
+    scheduling.
 
     Under the paged pool (``phys`` — per-slot physical frame indices), the
     bursts carry the pool's F frames instead of the dense [B, t] regions;
@@ -463,11 +472,15 @@ def _decode_step_scheduled(params, token, caches, pos, positions,
     space on the network's output, composing with the banked layout.
 
     With ``live`` (the fused-gather plan — see :func:`decode_step`), the
-    gather moves INTO the bursts: each pool leaf becomes a sparse-extent
-    stream banking only its live frames, the dense [B, T] view is a cheap
-    relabel of the live-sized output, and the update compacts back through
-    the inverse map before the sparse write scatters it into the pool —
-    so both networks move ``live`` frames, not ``pool`` frames."""
+    gather moves INTO the read burst: each pool leaf becomes a
+    sparse-extent stream banking only its live frames, and the dense [B, T]
+    view is a cheap relabel of the live-sized output.  On one device the
+    write burst is one small dense stream per leaf carrying the fresh
+    frames alone, landed at each slot's new frame (sentinels — idle slots,
+    padding — drop), so the pool is written ``B x layers`` frames a step
+    whatever its occupancy.  Pool-sharded (``shard_plans``), the updated
+    view compacts back through the inverse map (``dense_pos``) and the
+    sharded sparse write returns every live frame to its owning shard."""
     fab = cfg.resolved_fabric
     n = fab.n_ports
     if live is not None:
@@ -481,13 +494,14 @@ def _decode_step_scheduled(params, token, caches, pos, positions,
             reps *= s
         return reps
 
-    def leaf_gather_idx(leaf):
-        """The leaf's sparse read/scatter indices: the step's live frames,
-        tiled over the leaf's leading layer axis (unit leaves stack reps)."""
+    def leaf_gather_idx(leaf, idx):
+        """Per-pool frame indices ``idx`` (the step's live frames, or each
+        slot's new frame) tiled over the leaf's leading layer axis (unit
+        leaves stack reps) into its flattened line stream."""
         flat = _flat_frames(leaf)
         if flat.ndim == 3:                       # tail leaf: [F, N, D]
-            return live_idx
-        return cm.pool_rep_indices(live_idx, leaf_reps(leaf), flat.shape[-3])
+            return idx
+        return cm.pool_rep_indices(idx, leaf_reps(leaf), flat.shape[-3])
 
     def leaf_shard(leaf):
         """The leaf's ``shard=`` operand tuple: the step's pre-split
@@ -521,7 +535,7 @@ def _decode_step_scheduled(params, token, caches, pos, positions,
                 flat = _flat_frames(leaf)
                 sched.enqueue_read(
                     f"{kind}{i}/{leaf_name}", cm.kv_leaf_to_lines(flat),
-                    gather=leaf_gather_idx(leaf) if live is not None
+                    gather=leaf_gather_idx(leaf, live_idx) if live is not None
                     else None)
                 continue
             sched.enqueue_read(f"{kind}{i}/{leaf_name}",
@@ -569,31 +583,38 @@ def _decode_step_scheduled(params, token, caches, pos, positions,
                                  caches=caches, pos=pos, remat=False,
                                  pm_caches=pm)
 
-    # -- burst 2: updated port-major caches → line-major --------------------
+    # -- burst 2: the step's writes → line-major ------------------------------
+    # the layer scan emits each layer's fresh frames [lead?, B, Hkv, D]; the
+    # fused single-device step writes only those, the other branches
+    # rebuild the updated views from them and write those back whole
+    fresh_only = live is not None and shard_plans is None
+    if fresh_only:
+        new_frame = cm.step_frame_indices(live_idx, expand, pos)
+    landing = {}
     for kind, i in plan:
         for leaf_name in ("k", "v"):
-            new_pm = new_caches[kind][i][leaf_name + "_pm"]
+            name = f"{kind}{i}/{leaf_name}"
+            fresh = new_caches[kind][i][leaf_name + "_new"]
+            leaf = caches[kind][i][leaf_name]
+            if fresh_only:
+                banked, landing[name] = _fresh_write_stream(
+                    fresh, leaf_gather_idx(leaf, new_frame), n)
+                sched.enqueue_write(name, banked)
+                continue
+            new_pm = cm.pm_cache_write(pm[kind][i][leaf_name + "_pm"],
+                                        fresh, pos)
             if phys is not None and live is not None:
                 # compact the updated dense view back to live frames and
-                # scatter them into the pool through the sparse write burst
+                # scatter them into the pool through the sharded write
                 upd = jnp.moveaxis(new_pm, -4, -3)     # [lead?, Hkv, B, T, D]
                 flat = upd.reshape(upd.shape[:-3]
                                    + (upd.shape[-3] * upd.shape[-2],)
                                    + upd.shape[-1:])
                 compact = cm.gather_pool_frames(flat, dense_pos,
                                                 flat.ndim - 2)
-                leaf = caches[kind][i][leaf_name]
-                if shard_plans is not None:
-                    sched.enqueue_write(
-                        f"{kind}{i}/{leaf_name}",
-                        cm.port_major_to_banked(compact),
-                        shard=leaf_shard(leaf), into=leaf_stream(leaf))
-                    continue
-                sched.enqueue_write(
-                    f"{kind}{i}/{leaf_name}",
-                    cm.port_major_to_banked(compact),
-                    scatter=leaf_gather_idx(leaf),
-                    into=cm.kv_leaf_to_lines(_flat_frames(leaf)))
+                sched.enqueue_write(name, cm.port_major_to_banked(compact),
+                                    shard=leaf_shard(leaf),
+                                    into=leaf_stream(leaf))
                 continue
             if phys is not None:
                 # scatter the updated per-slot frames back into the
@@ -602,18 +623,39 @@ def _decode_step_scheduled(params, token, caches, pos, positions,
                 upd = jnp.moveaxis(new_pm, -4, -3)
                 new_pm = cm.scatter_pool_frames(pool_pm, upd, phys,
                                                 pool_pm.ndim - 2)
-            sched.enqueue_write(f"{kind}{i}/{leaf_name}",
-                                cm.port_major_to_banked(new_pm))
+            sched.enqueue_write(name, cm.port_major_to_banked(new_pm))
     sched.issue()
     lines_back = sched.commit()
     for kind, i in plan:
         shape = caches[kind][i]["k"].shape
-        new_caches[kind][i] = {
-            leaf_name: lines_back[f"{kind}{i}/{leaf_name}"].reshape(shape)
-            for leaf_name in ("k", "v")}
+        entry = {}
+        for leaf_name in ("k", "v"):
+            name = f"{kind}{i}/{leaf_name}"
+            lines = lines_back[name]
+            if fresh_only:
+                # land the fresh lines at their frames (sentinels drop);
+                # every other frame of the pool stays as it was
+                pool = cm.kv_leaf_to_lines(
+                    _flat_frames(caches[kind][i][leaf_name]))
+                lines = pool.at[landing[name]].set(lines, mode="drop")
+            entry[leaf_name] = lines.reshape(shape)
+        new_caches[kind][i] = entry
 
     x = cm.apply_norm(x, params["final_norm"], cfg.norm)
     return _emit_logits(params, x, cfg, draft), new_caches
+
+
+def _fresh_write_stream(fresh: jax.Array, idx: jax.Array, n: int):
+    """A layer stack's fresh frames ``[lead?, B, Hkv, D]`` and their pool
+    line indices ``idx`` (rep-major, as :func:`repro.models.common.
+    pool_rep_indices` tiles them) → the banked write-network input for one
+    dense stream, zero frames padding it to whole N-groups, and the landing
+    indices, padded with the sentinel so the padding drops."""
+    lines = fresh.reshape((-1,) + fresh.shape[-2:])      # [R*B, N, D]
+    pad = (-lines.shape[0]) % n
+    lines = jnp.pad(lines, ((0, pad), (0, 0), (0, 0)))
+    idx = jnp.pad(idx, (0, pad), constant_values=FRAME_SENTINEL)
+    return cm.port_major_to_banked(jnp.swapaxes(lines, 0, 1)), idx
 
 
 def _decode_step_paged_fallback(params, token, caches, pos, positions,

@@ -267,28 +267,122 @@ def _assert_step_equal(a, b):
         np.asarray(x), np.asarray(y)), a[1], b[1])
 
 
-@pytest.mark.parametrize("pack", ("packed", "pad"))
-@pytest.mark.parametrize("fold", (1, 2, "auto"))
-@pytest.mark.parametrize("kernels", (False, True))
-def test_decode_fused_vs_fallbacks_churny_table(pack, fold, kernels):
+def _steps_three_ways(cfg, caches, token, pos, table, ps, t_alloc,
+                      steps, fresh_pages):
+    """``steps`` decode steps through the fused contract, the
+    gather-after-burst scheduled step and the dense scheduled step (the
+    dense engine's, on the dense view the table reconstructs), each fed its
+    own caches and argmax tokens.  Before every step but the first each
+    live slot advances one position, mapping its next page from
+    ``fresh_pages`` when the position opens one; a slot whose row is all
+    ``-1`` stays idle.  After each step: logits and tokens equal across the
+    three, both pools (K and V) equal frame for frame between fused and
+    gather-after, and every valid position of the pool equal to the dense
+    cache."""
+    params = _params(cfg)
+    table = table.copy()
+    pos = np.asarray(pos, np.int32).copy()
+    idle = (table < 0).all(axis=1)
+    fresh_pages = list(fresh_pages)
+    fused_c = ga_c = caches
+    dense_c = jax.tree.map(
+        lambda leaf: cm.gather_pool_frames(
+            lm._flat_frames(leaf), cm.page_gather_indices(
+                jnp.asarray(table), ps, t_alloc), leaf.ndim - 4),
+        caches)
+    tok = {"fused": token, "ga": token, "dense": token}
+    for step in range(steps):
+        if step:
+            for s in np.flatnonzero(~idle):
+                pos[s] += 1
+                if table[s, pos[s] // ps] < 0:
+                    table[s, pos[s] // ps] = fresh_pages.pop(0)
+        pt, p = jnp.asarray(table), jnp.asarray(pos)
+        plan = tuple(jnp.asarray(a) for a in cm.page_live_plan(
+            table, ps, t_alloc, cfg.resolved_fabric.n_ports))
+
+        def sched():
+            return BurstScheduler(Fabric(cfg.resolved_fabric))
+
+        fused = api.decode_fn(params, tok["fused"], fused_c, p, cfg,
+                              sched=sched(), page_table=pt, page_size=ps,
+                              t_depth=t_alloc, live_plan=plan)
+        ga = api.decode_fn(params, tok["ga"], ga_c, p, cfg, sched=sched(),
+                           page_table=pt, page_size=ps, t_depth=t_alloc)
+        dense = api.decode_fn(params, tok["dense"], dense_c, p, cfg,
+                              sched=sched())
+        for out in (ga, dense):
+            np.testing.assert_array_equal(np.asarray(fused[0]),
+                                          np.asarray(out[0]))
+        fused_c, ga_c, dense_c = fused[1], ga[1], dense[1]
+        jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(b)), fused_c, ga_c)
+        phys = cm.page_gather_indices(pt, ps, t_alloc)
+        valid = np.arange(t_alloc)[None, :] <= pos[:, None]
+        valid[idle] = False
+        for pool, dn in zip(jax.tree.leaves(fused_c),
+                            jax.tree.leaves(dense_c)):
+            seen = cm.gather_pool_frames(lm._flat_frames(pool), phys,
+                                         pool.ndim - 4)
+            np.testing.assert_array_equal(np.asarray(seen)[..., valid, :, :],
+                                          np.asarray(dn)[..., valid, :, :])
+        for name, out in (("fused", fused), ("ga", ga), ("dense", dense)):
+            tok[name] = jnp.argmax(out[0][:, :1], axis=-1).astype(jnp.int32)
+        assert all(np.array_equal(np.asarray(tok["fused"]), np.asarray(t))
+                   for t in tok.values())
+    return fused_c
+
+
+_CHURNY_CASES = [
+    pytest.param(pack, fold, kernels, 1, id=f"{kernels}-{fold}-{pack}")
+    for pack in ("packed", "pad") for fold in (1, 2, "auto")
+    for kernels in (False, True)] + [
+    pytest.param("packed", "auto", kernels, 4, id=f"steps-{kernels}")
+    for kernels in (False, True)]
+
+
+@pytest.mark.parametrize("pack, fold, kernels, steps", _CHURNY_CASES)
+def test_decode_fused_vs_fallbacks_churny_table(pack, fold, kernels, steps):
     """A churny page table — a hole slot (all ``-1``), a partially-mapped
     slot, reused non-contiguous physical pages — decodes bit-identically
     through the fused contract, the gather-after-burst scheduled step and
-    the per-layer paged fallback: logits AND written-back pools."""
+    the per-layer paged fallback: logits AND written-back pools.
+
+    The ``steps`` cases run four steps against the gather-after step and
+    the dense engine's step, covering an idle slot (its write must drop), a
+    position that opens a freshly mapped page, and a write to the pool's
+    last frame: the fused write burst carries only the fresh frames, so
+    every other frame must come back exactly as it went in."""
     cfg = _cfg()
     cfg = dataclasses.replace(
         cfg, fabric=dataclasses.replace(cfg.resolved_fabric, pack=pack,
                                         word_fold=fold))
     ps, t_alloc, pool_pages = 3, 16, 14            # odd page size, slack pool
-    table = np.array([[5, 2, 9, -1, -1, -1],       # non-contiguous physmap
-                      [-1, -1, -1, -1, -1, -1],    # hole: retired slot
-                      [0, 13, 7, 4, -1, -1]], np.int32)
-    pos = [4, 0, 10]
-    caches, token, pos = _pool_decode_setup(cfg, table, pos, ps, t_alloc,
-                                            pool_pages)
     prev = ops.kernels_enabled()
     ops.use_kernels(kernels)
     try:
+        if steps > 1:
+            table = np.array([[5, 2, -1, -1, -1, -1],   # opens page 2 at pos 6
+                              [-1, -1, -1, -1, -1, -1],  # idle, stale pos
+                              [0, 13, 7, 4, -1, -1]],    # pos 5: frame 41
+                             np.int32)
+            pos = [5, 7, 3]
+            caches, token, _ = _pool_decode_setup(cfg, table, pos, ps,
+                                                  t_alloc, pool_pages)
+            out = _steps_three_ways(cfg, caches, token, pos, table, ps,
+                                    t_alloc, steps, fresh_pages=[9])
+            # the third step wrote the last frame (page 13, offset 2)
+            last = pool_pages * ps - 1
+            before = lm._flat_frames(caches["unit"][0]["k"])[:, last]
+            after = lm._flat_frames(out["unit"][0]["k"])[:, last]
+            assert not np.array_equal(np.asarray(before), np.asarray(after))
+            return
+        table = np.array([[5, 2, 9, -1, -1, -1],       # non-contiguous physmap
+                          [-1, -1, -1, -1, -1, -1],    # hole: retired slot
+                          [0, 13, 7, 4, -1, -1]], np.int32)
+        pos = [4, 0, 10]
+        caches, token, pos = _pool_decode_setup(cfg, table, pos, ps,
+                                                t_alloc, pool_pages)
         ref, ga, fused = _decode_three_ways(cfg, caches, token, pos, table,
                                             ps, t_alloc)
     finally:
@@ -382,6 +476,61 @@ def test_engine_fused_matches_dense_engine_bit_identical():
     eng = _assert_bit_identical_runs(cfg, arrivals)
     assert eng.fused                               # default contract engaged
     assert eng.fabric_stats.gather_fused_bursts > 0
+
+
+def _scan_outputs(jaxpr):
+    """The output shapes of every ``scan`` in ``jaxpr``, sub-jaxprs too."""
+    shapes = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            shapes += [tuple(v.aval.shape) for v in eqn.outvars]
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (tuple, list)) else (val,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    shapes += _scan_outputs(inner)
+    return shapes
+
+
+def test_engine_fused_write_census_is_fresh_frames_only():
+    """One engine step at two page-table occupancies writes the same words:
+    two streams (K, V) per paged layer stack of ``reps x B`` fresh frames,
+    padded to whole N-groups, whatever the live count its reads carry.  The
+    fused decode step carries no ``[reps, B, Hkv, T, D]`` view out of its
+    layer scan, and the sparse scatter kernel is not in its program."""
+    cfg = _cfg()
+    n, d = cfg.resolved_fabric.n_ports, cfg.resolved_head_dim
+    prev = ops.kernels_enabled()
+    ops.use_kernels(True)
+    try:
+        census = []
+        for prompts in ([[3, 1, 4, 1, 5]],
+                        [list(range(1, 41)), list(range(2, 38))]):
+            eng = ServingEngine(cfg, _params(cfg), max_slots=2, t_max=64,
+                                page_size=4)
+            for i, p in enumerate(prompts):
+                eng.submit(Request(i, np.asarray(p, np.int32),
+                                   max_new_tokens=4))
+            eng._admit()                       # install before the census
+            live = int(np.count_nonzero(
+                np.asarray(eng._decode_args()[5]) != SENTINEL))
+            before = eng.fabric_stats.words_written
+            eng.step()
+            census.append((live, eng.fabric_stats.words_written - before))
+        args = eng._decode_args()
+        text = eng._decode.lower(*args).as_text()
+        scans = _scan_outputs(jax.make_jaxpr(eng._decode)(*args).jaxpr)
+    finally:
+        ops.use_kernels(prev)
+    (live_lo, written_lo), (live_hi, written_hi) = census
+    assert live_lo < live_hi
+    reps, b, t = cfg.n_layers, eng.max_slots, eng.t_alloc
+    per_leaf = -(-reps * b // n) * n * n * d
+    assert written_lo == written_hi == 2 * per_leaf
+    assert (reps, b, n, d) in scans                # the fresh frames
+    assert (reps, b, n, t, d) not in scans
+    assert "gather_burst_network_tiles" in text
+    assert "scatter_burst_network_tiles" not in text
 
 
 # ---------------------------------------------------------------------------
