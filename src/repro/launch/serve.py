@@ -40,6 +40,7 @@ from repro.configs import get_config, get_smoke
 from repro.data import SyntheticLM
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
+from repro.models.moe import routes_dropless
 
 
 def main():
@@ -260,7 +261,10 @@ def main():
                       "banks the whole pool each step")
         else:
             print("fabric: decode step unscheduled (geometry fallback)")
-        if cfg.moe is not None:
+        if cfg.moe is not None and routes_dropless(cfg.moe):
+            print("moe dispatch: dropless (capacity_factor >= n_experts / "
+                  "top_k): grouped expert matmuls over every assignment")
+        elif cfg.moe is not None:
             print(f"moe dispatch: {fs.tokens_dropped} token assignments "
                   f"dropped at capacity over the whole run (sentinel rows "
                   f"in the dispatch scatter; residual passed through)")

@@ -1,4 +1,4 @@
-"""Mixture-of-Experts FFN with capacity-based dispatch.
+"""Mixture-of-Experts FFN: dropless grouped experts, or capacity dispatch.
 
 Ports-as-experts is the Medusa mapping (DESIGN.md §3.2): the router evenly
 partitions token bandwidth across experts with a *static* capacity (paper
@@ -6,18 +6,28 @@ observation 1 — even bandwidth partitioning), and the expert all-to-all can
 run either on XLA's native all-to-all (the "crossbar") or on the Medusa ring
 schedule (N-1 ``ppermute`` rotations — ``repro/parallel/collectives.py``).
 
-Dispatch is sort-based (no [T, E, C] one-hot): tokens are ranked within their
-expert by a stable sort over assignments; tokens past capacity are dropped
-(their residual passes through — standard capacity-factor semantics).
+Which path runs follows from the configuration.  When ``capacity_factor >=
+n_experts / top_k`` the capacity holds every assignment of the call and can
+never drop, so :func:`moe_apply` routes **dropless**: the ``T*k``
+assignments are stably sorted by expert, each token's row lands in its
+expert's contiguous run, and the SwiGLU runs as three grouped matmuls
+(``jax.lax.ragged_dot``) over exactly those ``T*k`` rows — no ``[E, C]``
+slots, no drop accounting, nothing sent to the host.  Below that factor the
+**capacity** path runs: tokens are ranked within their expert by the same
+stable sort; tokens past capacity are dropped (their residual passes
+through — standard capacity-factor semantics) and counted.
 
-Payload movement rides the fabric's burst contract: token→expert is the same
-logical→physical indirection as a page table, so dispatch is a
-scatter-indexed write into the ``[E, C]`` expert slots (capacity drops become
-sentinel rows, exactly like page-scatter sentinels) and combine is a
-gather-indexed read per assignment — both :class:`BurstScheduler`
-sparse-extent streams sharing the packed/fold/kernel lowering and counted in
-:class:`SchedulerStats`.  ``payload="route"`` keeps the bare ``fabric.route``
-gathers as the bit-parity reference (``tests/test_moe_fabric.py``).
+Payload movement rides the fabric's burst contract.  Dropless, dispatch is
+a gather-indexed read of each sorted row's token and combine a
+gather-indexed read of each assignment's sorted row.  With capacity,
+token→expert is the same logical→physical indirection as a page table, so
+dispatch is a scatter-indexed write into the ``[E, C]`` expert slots
+(capacity drops become sentinel rows, exactly like page-scatter sentinels)
+and combine is a gather-indexed read per assignment.  Every one is a
+:class:`BurstScheduler` sparse-extent stream sharing the packed/fold/kernel
+lowering and counted in :class:`SchedulerStats`.  ``payload="route"`` keeps
+the bare ``fabric.route`` gathers as the bit-parity reference of either
+layout (``tests/test_moe_fabric.py``, ``tests/test_moe_dropless.py``).
 """
 
 from __future__ import annotations
@@ -114,34 +124,75 @@ def _burst_dispatch(fabric: Fabric, xt: jax.Array, tok: jax.Array,
     return pool.reshape(ec_pad, d)[:ec]
 
 
-def _burst_combine(fabric: Fabric, y: jax.Array, keep: jax.Array,
-                   slot: jax.Array,
-                   stats: Optional[SchedulerStats]) -> jax.Array:
-    """Combine as one sparse-extent read burst: the expert output pool
-    ``[E*C, d]`` is the backing line stream and each assignment gathers its
-    slot's frame (dropped assignments gather the sentinel → zero frames,
-    matching the masked route)."""
+def _burst_gather(fabric: Fabric, rows: jax.Array, idx: jax.Array,
+                  name: str, stats: Optional[SchedulerStats]) -> jax.Array:
+    """``rows[idx]`` as one sparse-extent read burst: ``rows [R, d]`` is the
+    backing line stream (each row one frame of ``N`` ports) and ``idx [K]``
+    names the frame each output row gathers; sentinel indices gather zero
+    frames, matching a masked route."""
     n = fabric.n_ports
-    ec, d = y.shape
-    k_tot = slot.shape[0]
-    src = y
-    if ec % n:
-        src = jnp.concatenate([src, jnp.zeros((-ec % n, d), y.dtype)])
-    lines = src.reshape(-1, n, d // n)
-    gidx = jnp.where(keep, slot, FRAME_SENTINEL).astype(jnp.int32)
-    pad = -k_tot % n
-    if pad:
+    r, d = rows.shape
+    k_tot = idx.shape[0]
+    if r % n:
+        rows = jnp.concatenate([rows, jnp.zeros((-r % n, d), rows.dtype)])
+    lines = rows.reshape(-1, n, d // n)
+    gidx = idx.astype(jnp.int32)
+    if k_tot % n:
         gidx = jnp.concatenate(
-            [gidx, jnp.full((pad,), FRAME_SENTINEL, jnp.int32)])
+            [gidx, jnp.full((-k_tot % n,), FRAME_SENTINEL, jnp.int32)])
     sched = BurstScheduler(fabric, stats=stats)
-    sched.enqueue_read("moe/combine", lines, gather=gidx)
-    banked = sched.flush()["moe/combine"]                   # [K/N, N, N, d/N]
+    sched.enqueue_read(name, lines, gather=gidx)
+    banked = sched.flush()[name]                            # [K/N, N, N, d/N]
     return banked.swapaxes(1, 2).reshape(-1, d)[:k_tot]
+
+
+def routes_dropless(m) -> bool:
+    """Whether the capacity of ``m`` (a :class:`MoEConfig`) holds every
+    assignment of any call: ``capacity_factor >= n_experts / top_k``."""
+    return m.capacity_factor * m.top_k >= m.n_experts
+
+
+def _dropless_experts(p, xt: jax.Array, top_e: jax.Array, m,
+                      fabric: Fabric, payload: str,
+                      stats: Optional[SchedulerStats]) -> jax.Array:
+    """Every assignment's expert output ``[T*k, d]``, in assignment order.
+
+    A stable sort of the ``T*k`` assignments by expert gives each expert a
+    contiguous run of rows (``group_sizes`` rows; dead padded experts get
+    empty runs).  Dispatch gathers each sorted row's token, the SwiGLU runs
+    as grouped matmuls over the runs (float32 accumulation), and combine
+    gathers each assignment's row back out of the sorted order."""
+    kt = top_e.size
+    a = top_e.reshape(-1)
+    order = jnp.argsort(a, stable=True).astype(jnp.int32)   # sorted -> asg
+    src_tok = order // m.top_k
+    back = jnp.zeros((kt,), jnp.int32).at[order].set(
+        jnp.arange(kt, dtype=jnp.int32))                    # asg -> sorted
+    sizes = jnp.bincount(a, length=m.n_experts_padded).astype(jnp.int32)
+    if payload == "burst":
+        xs = _burst_gather(fabric, xt, src_tok, "moe/dispatch", stats)
+    else:
+        xs = fabric.route(xt, src_tok)
+    # the sorted rows shard as the capacity path's slots do (expert_cap)
+    xs = shard(xs, "expert_cap", "d_model")
+
+    def grouped(lhs, w):
+        return jax.lax.ragged_dot(lhs, w, sizes,
+                                  preferred_element_type=jnp.float32)
+
+    h = jax.nn.silu(grouped(xs, p["w_gate"])) * grouped(xs, p["w_up"])
+    h = shard(h, "expert_cap", None)
+    y = grouped(h.astype(xt.dtype), p["w_out"]).astype(xt.dtype)
+    y = shard(y, "expert_cap", "d_model")
+    if payload == "burst":
+        return _burst_gather(fabric, y, back, "moe/combine", stats)
+    return fabric.route(y, back)
 
 
 def moe_apply(p, x: jax.Array, cfg, stats: Optional[SchedulerStats] = None,
               payload: Optional[str] = None) -> jax.Array:
-    """``x [B, S, d]`` → MoE FFN output, top-k routing with capacity.
+    """``x [B, S, d]`` → MoE FFN output, top-k routing: dropless when
+    :func:`routes_dropless`, else with capacity.
 
     With ``moe.pad_to`` set, the expert dim is padded with dead experts the
     router can never select (logits only cover the real experts); capacity is
@@ -152,9 +203,10 @@ def moe_apply(p, x: jax.Array, cfg, stats: Optional[SchedulerStats] = None,
     ``"burst"`` (the default whenever the fabric banks and ``d_model`` splits
     across its ports) lowers both as :class:`BurstScheduler` sparse-extent
     streams; ``"route"`` is the bare ``fabric.route`` gather reference.  The
-    two are bit-identical — ``tests/test_moe_fabric.py`` holds the line
-    across the pack×fold×kernel matrix.  ``stats`` (or an ambient
-    :func:`dispatch_stats` context) receives the burst accounting plus the
+    two are bit-identical — ``tests/test_moe_fabric.py`` and
+    ``tests/test_moe_dropless.py`` hold the line across the pack×fold×kernel
+    matrix.  ``stats`` (or an ambient :func:`dispatch_stats` context)
+    receives the burst accounting plus, on the capacity path, the
     runtime-exact ``tokens_dropped`` counter.
     """
     m = cfg.moe
@@ -173,6 +225,12 @@ def moe_apply(p, x: jax.Array, cfg, stats: Optional[SchedulerStats] = None,
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_e = jax.lax.top_k(probs, m.top_k)                  # [T, k]
     top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    w = top_p.reshape(-1)[:, None].astype(x.dtype)
+
+    if routes_dropless(m):
+        gathered = _dropless_experts(p, xt, top_e, m, fabric, payload, stats)
+        out = (gathered * w).reshape(t, m.top_k, d).sum(axis=1)
+        return out.reshape(b, s, d)
 
     a = top_e.reshape(-1)                                         # [T*k]
     tok = jnp.arange(t * m.top_k) // m.top_k
@@ -216,13 +274,14 @@ def moe_apply(p, x: jax.Array, cfg, stats: Optional[SchedulerStats] = None,
     # combine: gather per assignment, weight, and reduce over the (static,
     # consecutive) top-k axis by reshape+sum — no scatter-add.
     if payload == "burst":
-        gathered = _burst_combine(fabric, y, keep, slot, stats)
+        gathered = _burst_gather(fabric, y,
+                                 jnp.where(keep, slot, FRAME_SENTINEL),
+                                 "moe/combine", stats)
     else:
         gathered = jnp.where(keep[:, None],
                              fabric.route(y, jnp.clip(slot, 0,
                                                       e_pad * cap - 1)),
                              0)
-    w = top_p.reshape(-1)[:, None].astype(x.dtype)
     out = (gathered * w).reshape(t, m.top_k, d).sum(axis=1)
     return out.reshape(b, s, d)
 

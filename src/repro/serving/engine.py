@@ -79,7 +79,9 @@ lifecycle stamps, in-process replica router) with the
 **Spans** (``jax.profiler.TraceAnnotation``) mark each step's host phases
 in the profiler's own trace, on the device planes' clock: ``serve.step``
 round :meth:`step`; inside it ``serve.admit`` (one ``serve.prefill`` per
-fresh request, metadata ``rid`` and ``prompt_len``, then ``serve.install``
+fresh request, metadata ``rid`` and ``prompt_len``, and for a
+mixture-of-experts model ``moe_rows``, the ``prompt_len * top_k`` expert
+assignments the prompt routes, computed on the host; then ``serve.install``
 for the wave's burst), ``serve.plan`` (the live-frame plan, metadata
 ``live`` and ``bucket`` frames), ``serve.decode`` (the jitted step's
 dispatch), ``serve.sample`` (the host waiting on the argmax) and
@@ -360,6 +362,15 @@ class ServingEngine:
                                          sched=sched, draft=draft)
 
         self._decode = jax.jit(_step)
+        # the prefill compiles once per prompt length: an eager call would
+        # re-trace and re-lower its layer scan (a new closure each call) on
+        # every admission while the device waits
+        t_alloc = self.t_alloc
+
+        def _prefill(p, tokens):
+            return api.prefill_fn(p, {"tokens": tokens}, cfg, t_alloc)
+
+        self._prefill = jax.jit(_prefill)
 
     @property
     def caches(self):
@@ -592,19 +603,19 @@ class ServingEngine:
                 full = np.concatenate([np.asarray(req.prompt, np.int32),
                                        np.asarray(req.generated[:-1],
                                                   np.int32)])
-                _, req_cache = api.prefill_fn(
-                    self.params, {"tokens": jnp.asarray(full)[None, :]},
-                    self.cfg, self.t_alloc)
+                _, req_cache = self._prefill(
+                    self.params, jnp.asarray(full)[None, :])
                 wave.append((slot, req_cache, len(full)))
         else:
             req = cand
             self.queue.remove(req)
             req.admitted_s = time.perf_counter()
-            with TraceAnnotation("serve.prefill", rid=req.rid,
-                                 prompt_len=len(req.prompt)):
+            meta = {"rid": req.rid, "prompt_len": len(req.prompt)}
+            if self.cfg.moe is not None:
+                meta["moe_rows"] = len(req.prompt) * self.cfg.moe.top_k
+            with TraceAnnotation("serve.prefill", **meta):
                 prompt = jnp.asarray(req.prompt)[None, :]
-                logits, req_cache = api.prefill_fn(
-                    self.params, {"tokens": prompt}, self.cfg, self.t_alloc)
+                logits, req_cache = self._prefill(self.params, prompt)
                 # page remap: only the pages the prompt occupies move
                 wave.append((slot, req_cache, len(req.prompt)))
                 self.active[slot] = req

@@ -100,6 +100,7 @@ def test_engine_spans_nest_and_carry_metadata(served):
     # one prefill per request, in admission order, with its id and length
     assert [(p[3]["rid"], p[3]["prompt_len"]) for p in by["serve.prefill"]] \
         == [(rid, n) for rid, n, _ in PROMPTS]
+    assert not any("moe_rows" in p[3] for p in by["serve.prefill"])
     # a wave of two, then one installed after the first retirement
     assert len(by["serve.install"]) == 2
     for p in by["serve.plan"]:
@@ -133,3 +134,20 @@ def test_decode_step_has_no_host_callback(served, tmp_path):
         jax.profiler.stop_trace()
     assert "callback" not in off
     assert on == off
+
+
+def test_moe_prefill_span_counts_routed_rows(tmp_path):
+    """A mixture-of-experts model's ``serve.prefill`` spans also carry
+    ``moe_rows``: the prompt's tokens times ``top_k``."""
+    prev = ops.kernels_enabled()
+    ops.use_kernels(False)
+    try:
+        cfg = dataclasses.replace(get_smoke("granite-moe-3b-a800m"),
+                                  dtype="float32")
+        _serve(cfg, api.init_params(cfg, KEY), str(tmp_path))
+    finally:
+        ops.use_kernels(prev)
+    prefills = [s[3] for s in _serve_spans(str(tmp_path))
+                if s[0] == "serve.prefill"]
+    assert [(p["rid"], p["prompt_len"], p["moe_rows"]) for p in prefills] \
+        == [(rid, n, n * cfg.moe.top_k) for rid, n, _ in PROMPTS]
